@@ -15,6 +15,9 @@ def main() -> None:
     if fast:
         os.environ.setdefault("BENCH_LARGE_N", "20000")
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (ccr, construction, kernels_bench, large_scale,
                             matvec, refinement, roofline_table, serving)
 

@@ -73,6 +73,7 @@ import numpy as np
 import jax
 
 from benchmarks.common import emit, json_path, write_json
+from repro.compile_cache import enable_compile_cache
 from repro.core.vdt import VariationalDualTree
 from repro.data.synthetic import secstr_like
 from repro.serving import (DeadlineExceeded, EngineFleet, PropagateEngine,
@@ -758,6 +759,7 @@ def main():
                     help="which closed-loop scenario to run (default: all)")
     args = ap.parse_args()
     scenarios = SCENARIOS if args.scenario == "all" else (args.scenario,)
+    enable_compile_cache()
     run(scenarios)
 
 

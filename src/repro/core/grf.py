@@ -43,16 +43,13 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.grf.grf import grf_feature_kernel
-from repro.kernels.grf.ref import grf_feature_matvec_ref
 from repro.kernels.grf.walkers import sample_walks as _sample_walks
-from repro.kernels.grf.walkers import walk_step
+from repro.kernels.grf.walkers import walk_step, walker_mean
 
 __all__ = ["CSRGraph", "DEFAULT_N_WALKERS", "MAX_RTOL_WALKERS",
            "walkers_for_rtol", "sample_walks", "grf_transition_action",
@@ -224,30 +221,15 @@ def sample_walks(graph: CSRGraph, *, n_steps: int, n_walkers: int,
                          p_halt=float(p_halt))
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _feature(pos, load, y, impl):
-    if impl == "ref":
-        return grf_feature_matvec_ref(pos, load, y)
-    if impl is not None:
-        raise ValueError(f"impl must be None (Pallas) or 'ref', got {impl!r}")
-    return grf_feature_kernel(pos, load, y, interpret=_interpret())
-
-
 def grf_transition_action(graph: CSRGraph, y, *, t: int,
                           n_walkers: int = DEFAULT_N_WALKERS, seed: int = 0,
-                          p_halt: float = 0.0, return_samples: bool = False,
-                          impl: Optional[str] = None):
+                          p_halt: float = 0.0, return_samples: bool = False):
     """Unbiased MC estimate of ``P^t @ Y`` without materializing P.
 
     ``y`` is ``(N,)`` or ``(N, C)``; the estimate matches its shape.  With
     ``return_samples=True`` also returns the per-walker contributions
     ``(N, m, C)`` whose walker-axis mean IS the estimate — the statistical
     harness derives its CLT confidence bounds from their spread.
-    ``impl`` selects the feature reduction (``None`` = Pallas kernel,
-    ``"ref"`` = jnp oracle); the estimate is the same either way.
     """
     y = jnp.asarray(y)
     squeeze = y.ndim == 1
@@ -255,7 +237,7 @@ def grf_transition_action(graph: CSRGraph, y, *, t: int,
     pos, load = sample_walks(graph, n_steps=int(t), n_walkers=n_walkers,
                              seed=seed, p_halt=p_halt)
     pos_t, load_t = pos[:, :, int(t)], load[:, :, int(t)]
-    est = _feature(pos_t, load_t, y2.astype(jnp.float32), impl)
+    est = walker_mean(pos_t, load_t, y2.astype(jnp.float32))
     est = est[:, 0] if squeeze else est
     if return_samples:
         samples = (jnp.take(y2.astype(jnp.float32), pos_t, axis=0)
@@ -266,7 +248,7 @@ def grf_transition_action(graph: CSRGraph, y, *, t: int,
 
 def grf_label_propagate(graph: CSRGraph, y0, alpha=0.01, n_iters: int = 500,
                         *, n_walkers: int = DEFAULT_N_WALKERS, seed: int = 0,
-                        p_halt: float = 0.0, impl: Optional[str] = None):
+                        p_halt: float = 0.0):
     """Eq.-15 label propagation estimated from one streamed walk set.
 
     ``y0`` is ``(N,)``, ``(N, C)`` or ``(batch, N, C)``; ``alpha`` a
@@ -297,7 +279,7 @@ def grf_label_propagate(graph: CSRGraph, y0, alpha=0.01, n_iters: int = 500,
             alpha = jnp.repeat(alpha, c)
         out = grf_label_propagate(
             graph, matvec_mod.fold_batch(y0), alpha=alpha, n_iters=n_iters,
-            n_walkers=n_walkers, seed=seed, p_halt=p_halt, impl=impl)
+            n_walkers=n_walkers, seed=seed, p_halt=p_halt)
         return matvec_mod.unfold_batch(out, batch, c)
     squeeze = y0.ndim == 1
     if squeeze:
@@ -311,14 +293,14 @@ def grf_label_propagate(graph: CSRGraph, y0, alpha=0.01, n_iters: int = 500,
     out = _lp_streamed(graph.nbr, graph.prob, graph.deg,
                        y0.astype(jnp.float32), alpha_cols,
                        jax.random.PRNGKey(int(seed)), int(n_iters),
-                       int(n_walkers), float(p_halt), impl)
+                       int(n_walkers), float(p_halt))
     return out[:, 0] if squeeze else out
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n_iters", "n_walkers", "p_halt", "impl"))
+                   static_argnames=("n_iters", "n_walkers", "p_halt"))
 def _lp_streamed(nbr, prob, deg, y0, alpha_cols, key, n_iters: int,
-                 n_walkers: int, p_halt: float, impl):
+                 n_walkers: int, p_halt: float):
     """One scan: advance walkers + accumulate series-weighted features.
 
     Carry is O(N * m + N * K): walker state plus the running estimate.
@@ -345,8 +327,8 @@ def _lp_streamed(nbr, prob, deg, y0, alpha_cols, key, n_iters: int,
         pos, load, alive, acc = carry
         pos, load, alive = walk_step(nbr, prob, deg, pos, load, alive,
                                      wkeys, t, p_halt)
-        feat = _feature(pos.reshape(n, n_walkers),
-                        load.reshape(n, n_walkers), y0, impl)
+        feat = walker_mean(pos.reshape(n, n_walkers),
+                           load.reshape(n, n_walkers), y0)
         acc = acc + coeff[t][None, :] * feat
         return (pos, load, alive, acc), None
 

@@ -81,6 +81,13 @@ AUTO_EXACT_MAX_N = 1024
 AUTO_GRF_MAX_DENSITY = 0.05
 AUTO_GRF_MIN_RTOL = 0.05
 
+# widest column slab one VDT walk materializes.  Each iteration gathers and
+# segment-sums (|B| slots, K) f32 arrays: compiled for a v5e at SecStr size
+# (2^17 leaves, |B| = 4N), the scan holds 2.25 GB of temporaries at K = 512
+# and 25.5 GB — past the chip's 16 GB — at the engine's widest K = 4096.
+# LP is column-independent, so wider walks run slab by slab (2.5 GB there).
+VDT_MAX_COLS = 512
+
 # the three concrete scan implementations every routing tag resolves to —
 # the serving tier's validate/group-key/warmup paths all share this
 # vocabulary, so a new backend lands in exactly one place
@@ -151,6 +158,35 @@ def label_propagate(
     return y
 
 
+def column_slabs(walk, cols, alpha):
+    """``walk(*cols, alpha)`` over slabs of at most :data:`VDT_MAX_COLS`.
+
+    ``cols`` are ``(rows, K)`` label arrays sharing the column axis and
+    ``alpha`` a scalar or ``(K,)``.  Narrow walks call ``walk`` unchanged;
+    wider ones zero-pad K to whole slabs (alpha 0: the pad columns stay
+    zero) and walk one slab after another, writing each into the output in
+    place, so at most one slab's temporaries are live.
+    """
+    k = cols[0].shape[1]
+    if k <= VDT_MAX_COLS:
+        return walk(*cols, alpha)
+    n_slabs = -(-k // VDT_MAX_COLS)
+    pad = n_slabs * VDT_MAX_COLS - k
+    cols = [jnp.pad(c, ((0, 0), (0, pad))) for c in cols]
+    alpha = jnp.pad(jnp.broadcast_to(alpha, (k,)), (0, pad))
+
+    def slab(i, out):
+        lo = i * VDT_MAX_COLS
+        part = [jax.lax.dynamic_slice_in_dim(c, lo, VDT_MAX_COLS, axis=1)
+                for c in cols]
+        al = jax.lax.dynamic_slice_in_dim(alpha, lo, VDT_MAX_COLS)
+        return jax.lax.dynamic_update_slice_in_dim(out, walk(*part, al), lo,
+                                                   axis=1)
+
+    out = jax.lax.fori_loop(0, n_slabs, slab, jnp.zeros_like(cols[0]))
+    return out[:, :k]
+
+
 @functools.partial(jax.jit, static_argnames=("L", "n_iters"))
 def lp_scan_leaforder(
     y0_leaf: jax.Array,      # (Np, K) seed labels in leaf order (ghosts 0)
@@ -168,16 +204,20 @@ def lp_scan_leaforder(
     term is re-masked every iteration — otherwise ghost garbage would feed
     back into the next CollectUp and corrupt real rows.  ``y0_leaf`` is zero
     at ghosts by construction, so masked rows stay identically zero and the
-    caller can gather real rows with ``tree.slot_of`` afterwards.
+    caller can gather real rows with ``tree.slot_of`` afterwards.  Walks
+    wider than :data:`VDT_MAX_COLS` run in column slabs.
     """
 
-    def step(y, _):
-        y = leaf_mask * (alpha * mpt_matvec_leaforder(y, a, b, q, L)) \
-            + (1.0 - alpha) * y0_leaf
-        return y, None
+    def walk(y0_leaf, alpha):
+        def step(y, _):
+            y = leaf_mask * (alpha * mpt_matvec_leaforder(y, a, b, q, L)) \
+                + (1.0 - alpha) * y0_leaf
+            return y, None
 
-    y, _ = jax.lax.scan(step, y0_leaf, None, length=n_iters)
-    return y
+        y, _ = jax.lax.scan(step, y0_leaf, None, length=n_iters)
+        return y
+
+    return column_slabs(walk, (y0_leaf,), alpha)
 
 
 @functools.partial(jax.jit, static_argnames=("L",))
@@ -210,11 +250,14 @@ def lp_scan_leaforder_resume(
     path; see ``kernels/fused_lp/batched.py``).
     """
 
-    def body(_, y):
-        return leaf_mask * (alpha * mpt_matvec_leaforder(y, a, b, q, L)) \
-            + (1.0 - alpha) * y0_leaf
+    def walk(y_leaf, y0_leaf, alpha):
+        def body(_, y):
+            return leaf_mask * (alpha * mpt_matvec_leaforder(y, a, b, q, L)) \
+                + (1.0 - alpha) * y0_leaf
 
-    return jax.lax.fori_loop(0, n_iters, body, y_leaf)
+        return jax.lax.fori_loop(0, n_iters, body, y_leaf)
+
+    return column_slabs(walk, (y_leaf, y0_leaf), alpha)
 
 
 def lp_scan_leaforder_segmented(

@@ -68,13 +68,11 @@ def pipeline_forward(
         # only the last stage holds outputs; replicate via psum
         return jax.lax.psum(outs, axis)
 
-    from jax.experimental.shard_map import shard_map
-
     spec_params = P(axis)  # stage dim sharded across pods
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_params, P()),       # input replicated; stage params split
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stage_params, x)

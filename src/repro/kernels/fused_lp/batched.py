@@ -36,10 +36,14 @@ keeps ``Y`` resident on device in the folded padded layout across all LP
 steps under one ``lax.scan`` (no per-step fold/unfold, no host sync), and
 slices back at the end — the serving engine's exact-backend hot loop.
 
-VMEM budget: the reuse kernel's accumulator is ``(bm, B*C)`` f32, so the
-folded width ``B*C`` should stay a few thousand columns at ``bm = 256``
-(e.g. ``B=32, C=128`` -> 4 MB of a ~16 MB/core VMEM).  The serving layer's
-width buckets and ``max_batch`` bound this by construction.
+VMEM budget: besides the ``(bm, B*C)`` f32 accumulator, Pallas
+double-buffers the ``(bn, B*C)`` label tile and the ``(bm, B*C)`` seed and
+output tiles, so a step holds about ``7 * 256 * K * 4`` bytes at folded
+width ``K = B*C`` with 256-row tiles.  Compiled for a v5e, ``K = 1024``
+fits Mosaic's 16 MiB default scoped limit, while ``K = 4096`` (the
+engine's widest layout, ``max_batch=32`` x width 128) needs 29.5 MiB at
+``d = 315``; :func:`~repro.kernels.fused_lp.fused_lp.vmem_params` raises
+the limit for such widths (``tests/test_tpu_compile.py`` pins both).
 
 Grid iteration order: cols innermost; VMEM scratch carries the running max
 m, normalizer s and weighted accumulator acc across column tiles; the last
@@ -61,7 +65,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.matvec import fold_batch, unfold_batch
-from repro.kernels.fused_lp.fused_lp import NEG_BIG, stream_tile_update, tile_config
+from repro.kernels.fused_lp.fused_lp import (NEG_BIG, stream_tile_update,
+                                             tile_config, vmem_params)
 
 __all__ = [
     "fused_lp_step_batched_kernel",
@@ -240,6 +245,7 @@ def _folded_call(xp_rows, xp_cols, yp, y0p, alpha_row, *,
             pltpu.VMEM((block_m, k), jnp.float32),
         ],
         interpret=interpret,
+        compiler_params=vmem_params(block_m, block_n, d, k),
     )(*operands)
 
 

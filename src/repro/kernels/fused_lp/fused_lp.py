@@ -26,9 +26,33 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["fused_lp_matvec_kernel", "stream_tile_update", "NEG_BIG",
-           "tile_config"]
+           "tile_config", "vmem_params"]
 
 NEG_BIG = -1e30
+
+# tile footprints up to this keep Mosaic's default scoped-VMEM limit
+# (16 MiB on v5e); larger ones raise it, to at most _VMEM_MAX of a v5e
+# TensorCore's 128 MiB
+_VMEM_KEEP_DEFAULT = 12 * 2**20
+_VMEM_MAX = 100 * 2**20
+
+
+def vmem_params(block_m: int, block_n: int, d: int, k: int):
+    """Compiler params that fit a streaming tile of label width ``k``.
+
+    The footprint is the double-buffered row/column point tiles and
+    ``(block, k)`` value, seed and output tiles plus the f32 accumulator —
+    about 29.5 MiB at ``k = 4096``, ``d = 315`` with 256-row tiles, past
+    Mosaic's 16 MiB default.  Widths whose footprint stays under 12 MiB
+    (every ``k <= 1024`` at ``d <= 500``) keep the default and so compile
+    to the same program as before; wider ones get twice their footprint,
+    room for Mosaic's own temporaries.
+    """
+    need = 4 * (2 * (block_m + block_n) * d
+                + 2 * (block_n + 2 * block_m) * k + block_m * k)
+    if need <= _VMEM_KEEP_DEFAULT:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=min(2 * need, _VMEM_MAX))
 
 
 def tile_config(divergence):
@@ -181,5 +205,6 @@ def fused_lp_matvec_kernel(
             pltpu.VMEM((block_m, c), jnp.float32),
         ],
         interpret=interpret,
+        compiler_params=vmem_params(block_m, block_n, d, c),
     )(xp_rows, xp_cols, yp)
     return out[:n]

@@ -1,7 +1,8 @@
 """jit'd public wrappers for the fused LP matvec / batched LP-step kernels.
 
-All wrappers fall back to Pallas interpret mode off-TPU so the same call
-sites run (slowly but correctly) on CPU test environments.
+Every wrapper asks :func:`repro.kernels.interpret_mode`: compiled kernels
+on a TPU, the Pallas interpreter on the CPU (slow but exact), and an error
+on any other platform.
 
 Batched dispatch
 ----------------
@@ -36,6 +37,7 @@ import functools
 
 import jax
 
+from repro.kernels import interpret_mode
 from repro.kernels.fused_lp.batched import (
     fused_lp_scan_batched_resume_kernel,
     fused_lp_scan_batched_reuse_kernel,
@@ -51,10 +53,6 @@ __all__ = ["fused_lp_matvec", "fused_lp_matvec_batched",
            "fused_lp_step_batched", "fused_lp_step_folded",
            "fused_lp_scan_folded", "fused_lp_scan_batched",
            "fused_lp_scan_folded_resume", "fused_lp_scan_batched_resume"]
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _static_div(divergence):
@@ -77,7 +75,7 @@ def _static_div(divergence):
 def _matvec_impl(x, y, sigma: float, block_m: int, block_n: int, divergence):
     return fused_lp_matvec_kernel(
         x, y, sigma, block_m=block_m, block_n=block_n,
-        interpret=_interpret(), divergence=divergence)
+        interpret=interpret_mode(), divergence=divergence)
 
 
 def fused_lp_matvec(x, y, sigma: float, block_m: int = 256,
@@ -93,7 +91,7 @@ def _step_folded_impl(x, y, y0, sigma: float, alpha,
                       block_m: int, block_n: int, divergence):
     return fused_lp_step_folded_kernel(
         x, y, y0, sigma, alpha, block_m=block_m, block_n=block_n,
-        interpret=_interpret(), divergence=divergence)
+        interpret=interpret_mode(), divergence=divergence)
 
 
 def fused_lp_step_folded(x, y, y0, sigma: float, alpha=1.0,
@@ -116,7 +114,7 @@ def _step_batched_reuse(x, y, y0, sigma: float, alpha,
                         divergence=None):
     return fused_lp_step_batched_reuse_kernel(
         x, y, y0, sigma, alpha, block_m=block_m, block_n=block_n,
-        interpret=_interpret(), divergence=divergence)
+        interpret=interpret_mode(), divergence=divergence)
 
 
 @functools.partial(jax.jit,
@@ -127,7 +125,7 @@ def _step_batched_perbatch(x, y, y0, sigma: float, alpha: float,
                            divergence=None):
     return fused_lp_step_batched_kernel(
         x, y, y0, sigma, alpha, block_m=block_m, block_n=block_n,
-        interpret=_interpret(), divergence=divergence)
+        interpret=interpret_mode(), divergence=divergence)
 
 
 def fused_lp_step_batched(x, y, y0, sigma: float, alpha=0.01,
@@ -171,7 +169,7 @@ def _scan_folded_impl(x, y0, sigma: float, alpha, n_iters: int,
                       block_m: int, block_n: int, divergence):
     return fused_lp_scan_folded_kernel(
         x, y0, sigma, alpha, int(n_iters), block_m=block_m, block_n=block_n,
-        interpret=_interpret(), divergence=divergence)
+        interpret=interpret_mode(), divergence=divergence)
 
 
 def fused_lp_scan_folded(x, y0, sigma: float, alpha, n_iters: int,
@@ -190,7 +188,7 @@ def _scan_batched_impl(x, y0s, sigma: float, alpha, n_iters: int,
                        block_m: int, block_n: int, divergence):
     return fused_lp_scan_batched_reuse_kernel(
         x, y0s, sigma, alpha, int(n_iters),
-        block_m=block_m, block_n=block_n, interpret=_interpret(),
+        block_m=block_m, block_n=block_n, interpret=interpret_mode(),
         divergence=divergence)
 
 
@@ -213,7 +211,7 @@ def _scan_folded_resume_impl(x, y, y0, sigma: float, alpha, n_iters,
                              block_m: int, block_n: int, divergence):
     return fused_lp_scan_folded_resume_kernel(
         x, y, y0, sigma, alpha, n_iters, block_m=block_m,
-        block_n=block_n, interpret=_interpret(), divergence=divergence)
+        block_n=block_n, interpret=interpret_mode(), divergence=divergence)
 
 
 def fused_lp_scan_folded_resume(x, y, y0, sigma: float, alpha, n_iters: int,
@@ -241,7 +239,7 @@ def _scan_batched_resume_impl(x, ys, y0s, sigma: float, alpha, n_iters,
                               block_m: int, block_n: int, divergence):
     return fused_lp_scan_batched_resume_kernel(
         x, ys, y0s, sigma, alpha, n_iters,
-        block_m=block_m, block_n=block_n, interpret=_interpret(),
+        block_m=block_m, block_n=block_n, interpret=interpret_mode(),
         divergence=divergence)
 
 

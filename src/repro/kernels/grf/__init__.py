@@ -1,9 +1,5 @@
-from repro.kernels.grf.grf import grf_feature_kernel
-from repro.kernels.grf.ops import grf_feature_matvec
-from repro.kernels.grf.ref import (dense_lp_ref, dense_power_action_ref,
-                                   grf_feature_matvec_ref)
-from repro.kernels.grf.walkers import sample_walks, walk_step
+from repro.kernels.grf.ref import dense_lp_ref, dense_power_action_ref
+from repro.kernels.grf.walkers import sample_walks, walk_step, walker_mean
 
-__all__ = ["grf_feature_kernel", "grf_feature_matvec",
-           "grf_feature_matvec_ref", "dense_power_action_ref",
-           "dense_lp_ref", "sample_walks", "walk_step"]
+__all__ = ["walker_mean", "dense_power_action_ref", "dense_lp_ref",
+           "sample_walks", "walk_step"]

@@ -1,24 +1,15 @@
-"""Pure-jnp oracles for the GRF kernels — scipy-free, dense, O(N^2).
+"""Pure-jnp oracles for the GRF estimator — scipy-free, dense, O(N^2).
 
-``grf_feature_matvec_ref`` is the take-based twin of the Pallas one-hot
-kernel (the parity anchor); ``dense_power_action_ref`` / ``dense_lp_ref``
-iterate the dense transition matrix directly — the ground truth the
-statistical harness (``tests/test_grf.py``) bounds the walker estimators
-against with CLT-derived tolerances.
+``dense_power_action_ref`` / ``dense_lp_ref`` iterate the dense transition
+matrix directly — the ground truth the statistical harness
+(``tests/test_grf.py``) bounds the walker estimators against with
+CLT-derived tolerances.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 
-__all__ = ["grf_feature_matvec_ref", "dense_power_action_ref",
-           "dense_lp_ref"]
-
-
-def grf_feature_matvec_ref(pos, load, y):
-    """``(1/m) * sum_w load[s, w] * y[pos[s, w], :]`` via ``jnp.take``."""
-    y = jnp.asarray(y, jnp.float32)
-    gathered = jnp.take(y, jnp.asarray(pos, jnp.int32), axis=0)  # (S, m, C)
-    return (gathered * jnp.asarray(load, jnp.float32)[..., None]).mean(axis=1)
+__all__ = ["dense_power_action_ref", "dense_lp_ref"]
 
 
 def dense_power_action_ref(p, y, t: int):

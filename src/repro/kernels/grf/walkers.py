@@ -24,6 +24,10 @@ consequences the tests pin:
 * the prefix property — walks of horizon ``T`` are exactly the first ``T``
   steps of horizon ``T' > T`` walks, so one walk set serves every
   intermediate power ``P^t`` of a label-propagation series at once.
+
+:func:`walker_mean` reduces one step's walker population to its feature
+estimate with an XLA gather — O(N * m * C) per step, which compiles on
+every backend.
 """
 from __future__ import annotations
 
@@ -32,7 +36,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-__all__ = ["walk_step", "sample_walks"]
+__all__ = ["walk_step", "sample_walks", "walker_mean"]
 
 
 def walk_step(nbr, prob, deg, pos, load, alive, wkeys, t, p_halt=0.0):
@@ -94,3 +98,14 @@ def sample_walks(nbr, prob, deg, key, *, n_steps: int, n_walkers: int,
     pos = jnp.moveaxis(pos, 0, -1).reshape(n, n_walkers, n_steps + 1)
     load = jnp.moveaxis(load, 0, -1).reshape(n, n_walkers, n_steps + 1)
     return pos, load
+
+
+def walker_mean(pos, load, y):
+    """``(1/m) * sum_w load[s, w] * y[pos[s, w], :]``: ``(S, m) x (N, C) -> (S, C)``.
+
+    The load-weighted walker mean that estimates one row block of
+    ``P^t @ Y`` from the step-``t`` walker positions and loads.
+    """
+    y = jnp.asarray(y, jnp.float32)
+    gathered = jnp.take(y, jnp.asarray(pos, jnp.int32), axis=0)  # (S, m, C)
+    return (gathered * jnp.asarray(load, jnp.float32)[..., None]).mean(axis=1)

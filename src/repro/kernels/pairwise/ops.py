@@ -1,18 +1,15 @@
-"""jit'd public wrapper: Pallas on TPU, interpret-mode elsewhere."""
+"""jit'd public wrapper: Pallas on TPU, interpret-mode on CPU."""
 import functools
 
 import jax
 
+from repro.kernels import interpret_mode
 from repro.kernels.pairwise.pairwise import pairwise_sq_dists_kernel
 
 __all__ = ["pairwise_sq_dists"]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n"))
 def pairwise_sq_dists(x, y, block_m: int = 256, block_n: int = 256):
     return pairwise_sq_dists_kernel(
-        x, y, block_m=block_m, block_n=block_n, interpret=not _on_tpu())
+        x, y, block_m=block_m, block_n=block_n, interpret=interpret_mode())

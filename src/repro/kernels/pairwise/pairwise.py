@@ -12,13 +12,11 @@ and the exact transition matrix) in the paper's §5 comparisons.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["pairwise_sq_dists_kernel", "pairwise_sq_dists"]
+__all__ = ["pairwise_sq_dists_kernel"]
 
 
 def _kernel(x_ref, y_ref, o_ref):
@@ -60,5 +58,3 @@ def pairwise_sq_dists_kernel(
     )(xp, yp)
     return out[:m, :n]
 
-
-pairwise_sq_dists = functools.partial(pairwise_sq_dists_kernel, interpret=False)

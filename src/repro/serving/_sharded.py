@@ -62,11 +62,12 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.core.label_prop import column_slabs
 from repro.core.matvec import collect_up, fold_batch, unfold_batch
 from repro.distributed.sharding import LEAF_AXIS, leaf_mesh, leaf_sharding
+from repro.kernels import interpret_mode
 from repro.serving._engine import PropagateEngine
 
 __all__ = ["ShardedPropagateEngine"]
@@ -267,10 +268,10 @@ class ShardedPropagateEngine(PropagateEngine):
         the first ``n_sharded`` args row-sharded over leaves, the rest
         replicated; the result row-sharded."""
         row = P(self._axis, None)
-        mapped = shard_map(
-            body, self._mesh,
+        mapped = jax.shard_map(
+            body, mesh=self._mesh,
             in_specs=tuple([row] * n_sharded + [P()] * n_rep),
-            out_specs=row, check_rep=False)
+            out_specs=row, check_vma=False)
         return jax.jit(
             mapped,
             in_shardings=tuple([self._row_sharding] * n_sharded
@@ -284,13 +285,17 @@ class ShardedPropagateEngine(PropagateEngine):
             K, axis = self._K, self._axis
 
             def body(y0_sh, mask_sh, a, b, q, alpha):
-                def step(y, _):
-                    y = mask_sh * (alpha * _sharded_matvec(
-                        y, a, b, q, L=L, K=K, axis=axis)) \
-                        + (1.0 - alpha) * y0_sh
-                    return y, None
-                y, _ = jax.lax.scan(step, y0_sh, None, length=int(n_iters))
-                return y
+                def walk(y0_sh, alpha):
+                    def step(y, _):
+                        y = mask_sh * (alpha * _sharded_matvec(
+                            y, a, b, q, L=L, K=K, axis=axis)) \
+                            + (1.0 - alpha) * y0_sh
+                        return y, None
+                    y, _ = jax.lax.scan(step, y0_sh, None,
+                                        length=int(n_iters))
+                    return y
+                # the single-device scan's column slabs, slab for slab
+                return column_slabs(walk, (y0_sh,), alpha)
 
             fn = self._jit_sharded(body, n_sharded=2, n_rep=4)
             self._jit_cache[key] = fn
@@ -306,11 +311,13 @@ class ShardedPropagateEngine(PropagateEngine):
             # single-device resume: one executable per shape covers every
             # segment length the scheduler can slice
             def body(y_sh, y0_sh, mask_sh, a, b, q, alpha, n_it):
-                def it(_, y):
-                    return mask_sh * (alpha * _sharded_matvec(
-                        y, a, b, q, L=L, K=K, axis=axis)) \
-                        + (1.0 - alpha) * y0_sh
-                return jax.lax.fori_loop(0, n_it, it, y_sh)
+                def walk(y_sh, y0_sh, alpha):
+                    def it(_, y):
+                        return mask_sh * (alpha * _sharded_matvec(
+                            y, a, b, q, L=L, K=K, axis=axis)) \
+                            + (1.0 - alpha) * y0_sh
+                    return jax.lax.fori_loop(0, n_it, it, y_sh)
+                return column_slabs(walk, (y_sh, y0_sh), alpha)
 
             fn = self._jit_sharded(body, n_sharded=3, n_rep=5)
             self._jit_cache[key] = fn
@@ -328,7 +335,7 @@ class ShardedPropagateEngine(PropagateEngine):
         axis = self._axis
         n_valid, inv = buf["n_valid"], buf["inv"]
         rps, tile_fn = buf["rps"], buf["tile_fn"]
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
         from repro.kernels.fused_lp.batched import _folded_call
 
         def step(x_rows, x_full, y_sh, y0_sh, al, row_base):

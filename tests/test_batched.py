@@ -227,6 +227,31 @@ def test_label_propagate_exact_backend_matches_dense(small_fitted_vdt):
         np.testing.assert_allclose(got_b[b], want_b, rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("resume", [False, True])
+def test_wide_walk_runs_in_column_slabs(small_fitted_vdt, resume):
+    """A walk wider than VDT_MAX_COLS runs slab by slab and equals the
+    narrow walks of its column slices bit for bit (LP is
+    column-independent), monolithic and resumed alike."""
+    from repro.core.label_prop import VDT_MAX_COLS
+
+    x, vdt = small_fitted_vdt
+    k = 2 * VDT_MAX_COLS + 3
+    r = np.random.RandomState(5)
+    y0 = (r.rand(x.shape[0], k) > 0.8).astype(np.float32)
+    alpha = r.uniform(0.0, 0.5, size=k).astype(np.float32)
+
+    def walk(cols):
+        if resume:
+            return vdt.label_propagate_resume(2 * y0[:, cols], y0[:, cols],
+                                              alpha=alpha[cols], n_iters=7)
+        return vdt.label_propagate(y0[:, cols], alpha=alpha[cols], n_iters=7)
+
+    wide = np.asarray(walk(slice(None)))
+    for lo in range(0, k, VDT_MAX_COLS):
+        cols = slice(lo, min(lo + VDT_MAX_COLS, k))
+        np.testing.assert_array_equal(wide[:, cols], np.asarray(walk(cols)))
+
+
 def test_label_propagate_rejects_unknown_backend(small_fitted_vdt):
     _, vdt = small_fitted_vdt
     with pytest.raises(ValueError):

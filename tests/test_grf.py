@@ -57,8 +57,7 @@ def test_transition_action_unbiased(graph, dense_p, t):
     y = rng.randn(N).astype(np.float32)
     oracle = dense_power_action_ref(dense_p, y, t)
     est, samples = grf_transition_action(
-        graph, y, t=t, n_walkers=2048, seed=t, return_samples=True,
-        impl="ref")
+        graph, y, t=t, n_walkers=2048, seed=t, return_samples=True)
     assert_unbiased(np.asarray(samples), np.asarray(oracle), axis=1,
                     what=f"P^{t} y walker mean")
     np.testing.assert_allclose(np.asarray(est),
@@ -75,7 +74,7 @@ def test_transition_action_unbiased_with_halting(graph, dense_p):
     oracle = dense_power_action_ref(dense_p, y, t)
     _, samples = grf_transition_action(
         graph, y, t=t, n_walkers=4096, seed=5, p_halt=0.15,
-        return_samples=True, impl="ref")
+        return_samples=True)
     assert_unbiased(np.asarray(samples), np.asarray(oracle), axis=1,
                     what="terminating-walk mean")
 
@@ -92,7 +91,7 @@ def test_variance_decays_with_walkers(graph, dense_p):
         out = []
         for seed in range(reps):
             est = grf_transition_action(graph, y, t=t, n_walkers=m,
-                                        seed=1000 + seed, impl="ref")
+                                        seed=1000 + seed)
             out.append(np.mean((np.asarray(est, np.float64) - oracle) ** 2))
         return out
 
@@ -107,15 +106,13 @@ def test_row_stochastic_and_nonnegative(graph):
     (loads are products of non-negative multipliers)."""
     ones = np.ones(N, np.float32)
     _, samples = grf_transition_action(graph, ones, t=5, n_walkers=2048,
-                                       seed=3, return_samples=True,
-                                       impl="ref")
+                                       seed=3, return_samples=True)
     assert_unbiased(np.asarray(samples), ones, axis=1,
                     what="row-sum estimate")
     assert (np.asarray(samples) >= 0.0).all()
 
     y = np.abs(np.random.RandomState(4).randn(N, 3)).astype(np.float32)
-    est = grf_transition_action(graph, y, t=4, n_walkers=64, seed=9,
-                                impl="ref")
+    est = grf_transition_action(graph, y, t=4, n_walkers=64, seed=9)
     assert (np.asarray(est) >= 0.0).all()
 
 
@@ -151,7 +148,7 @@ def test_label_propagate_deterministic_and_fold_parity(graph):
     rng = np.random.RandomState(6)
     y0a = rng.rand(N, 2).astype(np.float32)
     y0b = rng.rand(N, 2).astype(np.float32)
-    kw = dict(n_iters=6, n_walkers=16, seed=12, impl="ref")
+    kw = dict(n_iters=6, n_walkers=16, seed=12)
     solo_a = np.asarray(grf_label_propagate(graph, y0a, alpha=0.05, **kw))
     again = np.asarray(grf_label_propagate(graph, y0a, alpha=0.05, **kw))
     assert np.array_equal(solo_a, again)
@@ -162,18 +159,21 @@ def test_label_propagate_deterministic_and_fold_parity(graph):
     assert np.array_equal(batched[1], solo_b)
 
 
-def test_feature_kernel_matches_ref(graph):
-    """The Pallas one-hot-matmul feature reduction equals the jnp oracle."""
+def test_walker_mean_matches_numpy_loop():
+    """The feature reduction is the load-weighted walker mean of gathered
+    label rows, element for element."""
+    from repro.kernels.grf.walkers import walker_mean
+
     rng = np.random.RandomState(13)
-    y = rng.randn(N, 3).astype(np.float32)
-    t = 4
-    a = grf_transition_action(graph, y, t=t, n_walkers=32, seed=2)
-    b = grf_transition_action(graph, y, t=t, n_walkers=32, seed=2,
-                              impl="ref")
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+    pos = rng.randint(0, 7, size=(5, 3))
+    load = rng.rand(5, 3).astype(np.float32)
+    y = rng.randn(7, 2).astype(np.float32)
+    want = np.zeros((5, 2), np.float64)
+    for s in range(5):
+        for w in range(3):
+            want[s] += load[s, w] * y[pos[s, w]] / 3
+    np.testing.assert_allclose(np.asarray(walker_mean(pos, load, y)), want,
                                rtol=1e-5, atol=1e-6)
-    with pytest.raises(ValueError, match="impl"):
-        grf_transition_action(graph, y, t=1, n_walkers=4, impl="fast")
 
 
 # -------------------------------------------------- differential: LP
@@ -188,7 +188,7 @@ def test_lp_unbiased_vs_dense_reference(graph, dense_p):
     ests = np.stack([
         np.asarray(grf_label_propagate(graph, y0, alpha=alpha,
                                        n_iters=n_iters, n_walkers=256,
-                                       seed=s, impl="ref"))
+                                       seed=s))
         for s in range(reps)])
     assert_unbiased(ests, oracle, axis=0, what="grf LP vs dense_lp_ref")
 
@@ -198,10 +198,10 @@ def test_lp_alpha_zero_and_zero_iters(graph):
     the seed labels untouched, and so does n_iters=0 (the t=0 term)."""
     y0 = np.random.RandomState(8).rand(N, 2).astype(np.float32)
     out0 = grf_label_propagate(graph, y0, alpha=0.0, n_iters=5,
-                               n_walkers=4, seed=0, impl="ref")
+                               n_walkers=4, seed=0)
     np.testing.assert_allclose(np.asarray(out0), y0, rtol=1e-6, atol=1e-6)
     outz = grf_label_propagate(graph, y0, alpha=0.3, n_iters=0,
-                               n_walkers=4, seed=0, impl="ref")
+                               n_walkers=4, seed=0)
     np.testing.assert_allclose(np.asarray(outz), y0, rtol=1e-6, atol=1e-6)
 
 

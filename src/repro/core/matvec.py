@@ -123,14 +123,23 @@ def mpt_matvec_leaforder(
     q: jax.Array,            # (cap,)  block parameters (0 where inactive)
     L: int,
 ) -> jax.Array:
-    """(QY) in leaf order; any leading batch dims ride along level-major."""
+    """(QY) in leaf order; any leading batch dims ride along level-major.
+
+    Each phase runs under a ``jax.named_scope`` (``vdt.collect_up``,
+    ``vdt.gather``, ``vdt.segment_sum``, ``vdt.distribute_down``), so the
+    ops of a profile carry the phase in their op name.
+    """
     n_nodes = (1 << (L + 1)) - 1
-    t = collect_up(y_leaf, L)                       # (..., n_nodes, C)
-    c_block = q[:, None] * jnp.take(t, b, axis=-2)  # (..., cap, C)
-    c_block = jnp.moveaxis(c_block, -2, 0)          # (cap, ..., C)
-    c_node = jax.ops.segment_sum(c_block, a, num_segments=n_nodes)
-    c_node = jnp.moveaxis(c_node, 0, -2)            # (..., n_nodes, C)
-    return _distribute_down(c_node, L)
+    with jax.named_scope("vdt.collect_up"):
+        t = collect_up(y_leaf, L)                       # (..., n_nodes, C)
+    with jax.named_scope("vdt.gather"):
+        c_block = q[:, None] * jnp.take(t, b, axis=-2)  # (..., cap, C)
+    with jax.named_scope("vdt.segment_sum"):
+        c_block = jnp.moveaxis(c_block, -2, 0)          # (cap, ..., C)
+        c_node = jax.ops.segment_sum(c_block, a, num_segments=n_nodes)
+        c_node = jnp.moveaxis(c_node, 0, -2)            # (..., n_nodes, C)
+    with jax.named_scope("vdt.distribute_down"):
+        return _distribute_down(c_node, L)
 
 
 def mpt_matvec(

@@ -17,6 +17,7 @@ recovers the paper's schedule exactly.
 """
 from __future__ import annotations
 
+import time
 from typing import Tuple
 
 import jax
@@ -164,6 +165,8 @@ def refine_to_budget(
     refit_sigma: bool = False,
     divergence=None,
     stale: np.ndarray | None = None,
+    *,
+    stats=None,
 ) -> Tuple[QState, jax.Array]:
     """Refine until ``n_active >= max_blocks``; returns final (QState, sigma).
 
@@ -175,6 +178,14 @@ def refine_to_budget(
     whose stats were patched by streaming mutations — see
     :func:`refine_topk`; refined slots are cleared in place so a streaming
     model's staleness bookkeeping drains as the budget is spent.
+
+    Each round records three profiler spans (``jax.profiler``
+    ``TraceAnnotation``): ``fit.refine.gains`` (the gains and their copy to
+    the host), ``fit.refine.select`` (:func:`refine_topk` on the host) and
+    ``fit.refine.qopt`` (re-optimizing q, and sigma with ``refit_sigma``);
+    ``docs/ARCHITECTURE.md`` ("Profiling a fit") reads them. ``stats`` (a :class:`~repro.core.vdt.VdtStats`), where given, counts
+    the rounds in ``refine_rounds`` and adds the host clock's seconds of
+    selection to ``refine_select_s``.
     """
     from repro.core.divergence import bind_divergence
     from repro.core.sigma import sigma_star  # local import to avoid cycle
@@ -184,18 +195,25 @@ def refine_to_budget(
                     jnp.asarray(bp.active), sigma, divergence=div)
     while bp.n_active < max_blocks:
         k = min(batch, max(1, (max_blocks - bp.n_active) // 2))
-        gains = refinement_gains(
-            tree, jnp.asarray(bp.a), jnp.asarray(bp.b), jnp.asarray(bp.active),
-            qs.log_q, sigma, divergence=div,
-        )
-        done = refine_topk(bp, tree, np.asarray(gains), k, stale=stale)
+        with jax.profiler.TraceAnnotation("fit.refine.gains"):
+            gains = np.asarray(refinement_gains(
+                tree, jnp.asarray(bp.a), jnp.asarray(bp.b), jnp.asarray(bp.active),
+                qs.log_q, sigma, divergence=div,
+            ))
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("fit.refine.select"):
+            done = refine_topk(bp, tree, gains, k, stale=stale)
+        if stats is not None:
+            stats.refine_rounds += 1
+            stats.refine_select_s += time.perf_counter() - t0
         if done == 0:
             break
-        qs = optimize_q(tree, jnp.asarray(bp.a), jnp.asarray(bp.b),
-                        jnp.asarray(bp.active), sigma, divergence=div)
-        if refit_sigma:
-            sigma = sigma_star(tree, jnp.asarray(bp.a), jnp.asarray(bp.b),
-                               jnp.asarray(bp.active), qs.log_q, divergence=div)
+        with jax.profiler.TraceAnnotation("fit.refine.qopt"):
             qs = optimize_q(tree, jnp.asarray(bp.a), jnp.asarray(bp.b),
                             jnp.asarray(bp.active), sigma, divergence=div)
+            if refit_sigma:
+                sigma = sigma_star(tree, jnp.asarray(bp.a), jnp.asarray(bp.b),
+                                   jnp.asarray(bp.active), qs.log_q, divergence=div)
+                qs = optimize_q(tree, jnp.asarray(bp.a), jnp.asarray(bp.b),
+                                jnp.asarray(bp.active), sigma, divergence=div)
     return qs, sigma

@@ -38,6 +38,10 @@ class VdtStats:
     build_tree_s: float = 0.0
     init_qopt_s: float = 0.0
     refine_s: float = 0.0
+    # rounds of greedy refinement, and the host clock's seconds of block
+    # selection within refine_s (refine.refine_to_budget)
+    refine_rounds: int = 0
+    refine_select_s: float = 0.0
     sigma_iters: int = 0
     n_blocks: int = 0
     bound: float = 0.0
@@ -148,7 +152,7 @@ class VariationalDualTree:
             t0 = time.perf_counter()
             qs, sig = refine_mod.refine_to_budget(
                 bp, tree, sig, max_blocks, batch=refine_batch,
-                refit_sigma=learn_sigma, divergence=bound_div,
+                refit_sigma=learn_sigma, divergence=bound_div, stats=stats,
             )
             jax.block_until_ready(qs.log_q)
             stats.refine_s = time.perf_counter() - t0
@@ -274,8 +278,17 @@ class VariationalDualTree:
           relative error ~ ``1/sqrt(n_walkers)`` — and ``seed`` makes the
           estimate deterministic (bit-identical per ``(seed, shapes)``).
           Both are ignored by the other backends.
+
+        Profiler spans (``jax.profiler.TraceAnnotation``, nearly free when
+        no trace runs), none inside another: ``vdt.h2d`` around each copy
+        from the host; on the vdt backend ``vdt.permute`` around the fold,
+        the row->leaf scatter, the leaf->row gather and the unfold, and
+        ``vdt.scan`` around the launch of the walk, which returns before
+        the device finishes it.
         """
-        y0 = jnp.asarray(y0)
+        if not isinstance(y0, jax.Array):
+            with jax.profiler.TraceAnnotation("vdt.h2d"):
+                y0 = jnp.asarray(y0)
         if not jnp.issubdtype(y0.dtype, jnp.floating):
             y0 = y0.astype(jnp.float32)
         if backend not in ("vdt", "exact", "grf"):
@@ -305,29 +318,36 @@ class VariationalDualTree:
                 raise ValueError(
                     f"batched label_propagate wants (batch, N, C), got {y0.shape}")
             batch, _, c = y0.shape
-            alpha = jnp.asarray(alpha, y0.dtype)
-            if alpha.ndim == 1:
-                if alpha.shape[0] != batch:
-                    raise ValueError(
-                        f"per-request alpha wants shape ({batch},), got {alpha.shape}")
-                # folded column b*C + ch belongs to request b (see fold_batch)
-                alpha = jnp.repeat(alpha, c)
-            out = self.label_propagate(matvec_mod.fold_batch(y0), alpha=alpha,
-                                       n_iters=n_iters, batched=False)
-            return matvec_mod.unfold_batch(out, batch, c)
+            with jax.profiler.TraceAnnotation("vdt.h2d"):
+                alpha = jnp.asarray(alpha, y0.dtype)
+            with jax.profiler.TraceAnnotation("vdt.permute"):
+                if alpha.ndim == 1:
+                    if alpha.shape[0] != batch:
+                        raise ValueError(
+                            f"per-request alpha wants shape ({batch},), got {alpha.shape}")
+                    # folded column b*C + ch belongs to request b (see fold_batch)
+                    alpha = jnp.repeat(alpha, c)
+                y = matvec_mod.fold_batch(y0)
+            out = self.label_propagate(y, alpha=alpha, n_iters=n_iters,
+                                       batched=False)
+            with jax.profiler.TraceAnnotation("vdt.permute"):
+                return matvec_mod.unfold_batch(out, batch, c)
 
         squeeze = y0.ndim == 1
         if squeeze:
             y0 = y0[:, None]
         tree = self.tree
         a, b, _, q, mask = self._dispatch_buffers()
-        y_leaf = jnp.zeros((tree.n_leaves, y0.shape[1]), y0.dtype)
-        y_leaf = y_leaf.at[tree.slot_of].set(y0)
-        out_leaf = lp_scan_leaforder(
-            y_leaf, mask, a, b, q, jnp.asarray(alpha, y0.dtype),
-            tree.L, int(n_iters),
-        )
-        out = out_leaf[tree.slot_of]
+        with jax.profiler.TraceAnnotation("vdt.permute"):
+            y_leaf = jnp.zeros((tree.n_leaves, y0.shape[1]), y0.dtype)
+            y_leaf = y_leaf.at[tree.slot_of].set(y0)
+        with jax.profiler.TraceAnnotation("vdt.scan"):
+            out_leaf = lp_scan_leaforder(
+                y_leaf, mask, a, b, q, jnp.asarray(alpha, y0.dtype),
+                tree.L, int(n_iters),
+            )
+        with jax.profiler.TraceAnnotation("vdt.permute"):
+            out = out_leaf[tree.slot_of]
         return out[:, 0] if squeeze else out
 
     def label_propagate_resume(self, y, y0, alpha=0.01, n_iters: int = 500,
@@ -432,14 +452,16 @@ class VariationalDualTree:
         if stream is not None and stream.owner() is self:
             # streaming-touched blocks get the budget first
             stale = stream.stale
+        t0 = time.perf_counter()
         self.qstate, self.sigma = refine_mod.refine_to_budget(
             self.bp, self.tree, self.sigma, max_blocks, batch=batch,
-            divergence=self.bound_divergence, stale=stale,
+            divergence=self.bound_divergence, stale=stale, stats=self.stats,
         )
         self._serve_cache = None  # a/b/q/active all changed
         self._stream = None  # refinement regrew the partition; mirrors stale
         self.stats.n_blocks = self.bp.n_active
         self.stats.bound = float(self.qstate.bound)
+        self.stats.refine_s += time.perf_counter() - t0
 
     def _check_finite_q(self) -> None:
         """Guard against a divergence/domain mismatch poisoning the model.

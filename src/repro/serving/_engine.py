@@ -114,6 +114,20 @@ tree's dispatch buffers (block indices, ``exp(log_q)``, leaf mask) are
 cached device-side on the ``VariationalDualTree`` itself — steady-state
 iterations allocate nothing on the host path.
 
+Profiler spans
+--------------
+Each host step records a ``jax.profiler.TraceAnnotation`` span, on the
+device trace's clock when a profiler runs and about a microsecond each
+when none does: ``serve.submit`` (the body of :meth:`submit` up to the
+queue push), ``serve.linger``, ``serve.drain``, ``serve.stage`` (filling
+the staging stack), ``serve.wait`` (waiting for the walk's result),
+``serve.d2h`` (the result copy) and ``serve.resolve`` (setting the
+futures); ``label_propagate`` adds ``vdt.*`` spans between staging and the
+wait.  The spans are leaves, none inside another, so an idle gap of the
+device is named by the one step it falls in.  Copies to the device are laid
+out by the runtime on its own threads after ``vdt.h2d`` returns: the device
+idles for them under ``serve.wait``.
+
 Concurrency contract
 --------------------
 ``submit`` is thread-safe and may be called from any thread (or wrapped for
@@ -475,6 +489,21 @@ class PropagateEngine(Engine):
         :class:`DeadlineExceeded` if the deadline passes while it is still
         queued.
         """
+        with jax.profiler.TraceAnnotation("serve.submit"):
+            fut = self._enqueue(request, block, timeout)
+        if self._closed and fut.cancel():
+            # lost the race with shutdown(): the entry landed after (or
+            # during) the final flush, so nobody may ever drain it — cancel
+            # rather than hand back a future that could hang forever
+            self._metrics.count("cancelled")
+            raise RuntimeError("engine is shut down")
+        self._metrics.count("submitted")
+        return fut
+
+    def _enqueue(self, request: PropagateRequest, block: bool,
+                 timeout: Optional[float]) -> Future:
+        """Validate ``request``, pin its epoch and push it; the body of
+        :meth:`submit` up to the queue push."""
         if self._closed:
             raise RuntimeError("engine is shut down")
         # pin the serving epoch: validate against the current epoch's shape
@@ -530,13 +559,6 @@ class PropagateEngine(Engine):
                 self._retire_locked()
             self._metrics.count("rejected")
             raise
-        if self._closed and fut.cancel():
-            # lost the race with shutdown(): the entry landed after (or
-            # during) the final flush, so nobody may ever drain it — cancel
-            # rather than hand back a future that could hang forever
-            self._metrics.count("cancelled")
-            raise RuntimeError("engine is shut down")
-        self._metrics.count("submitted")
         return fut
 
     # ------------------------------------------------------------ scheduling
@@ -549,7 +571,8 @@ class PropagateEngine(Engine):
         it deterministically.
         """
         self._prune_staging()
-        live, cancelled, expired = self._queue.drain(self.max_batch)
+        with jax.profiler.TraceAnnotation("serve.drain"):
+            live, cancelled, expired = self._queue.drain(self.max_batch)
         if cancelled:
             self._metrics.count("cancelled", len(cancelled))
             self._release(cancelled)
@@ -663,7 +686,8 @@ class PropagateEngine(Engine):
                 if not self._queue.wait_nonempty(timeout=0.05):
                     continue
                 if self.max_wait_ms > 0:
-                    self._linger()
+                    with jax.profiler.TraceAnnotation("serve.linger"):
+                        self._linger()
                 self.step()
             except Exception:  # never let the scheduler thread die silently
                 # per-group errors were already delivered via set_exception;
@@ -730,15 +754,16 @@ class PropagateEngine(Engine):
             group.sort(key=lambda e: e.seq)  # deterministic batch layout
             urgent_resolved = 0
             try:
-                bb = batch_bucket(len(group), self.max_batch)
-                stack = self._staging.setdefault(
-                    (n, bb, cb), np.zeros((bb, n, cb), np.float32))
-                stack.fill(0.0)
-                alphas = np.zeros((bb,), np.float32)  # padding rows: alpha 0
-                for k, entry in enumerate(group):
-                    y0 = entry.request.y0
-                    stack[k, :, :y0.shape[1]] = y0
-                    alphas[k] = entry.request.alpha
+                with jax.profiler.TraceAnnotation("serve.stage"):
+                    bb = batch_bucket(len(group), self.max_batch)
+                    stack = self._staging.setdefault(
+                        (n, bb, cb), np.zeros((bb, n, cb), np.float32))
+                    stack.fill(0.0)
+                    alphas = np.zeros((bb,), np.float32)  # padding rows: alpha 0
+                    for k, entry in enumerate(group):
+                        y0 = entry.request.y0
+                        stack[k, :, :y0.shape[1]] = y0
+                        alphas[k] = entry.request.alpha
                 out, urgent_resolved = self._propagate_group(
                     group, stack, alphas, n_iters, backend, preemptible,
                     vdt, n_walkers=n_walkers)
@@ -756,19 +781,21 @@ class PropagateEngine(Engine):
             # resolves to a zero-copy view sliced to its true width —
             # host-transfer cost per dispatch is one contiguous array,
             # however many requests coalesced into it
-            slab = ResultSlab(
-                data=np.asarray(out),
-                widths=tuple(e.request.y0.shape[1] for e in group))
-            t_done = self._clock()
-            for k, entry in enumerate(group):
-                entry.future.set_result(slab.view(k))
-                self._metrics.record_latency(t_done - entry.t_submit)
-                if entry.t_deadline is not None and t_done > entry.t_deadline:
-                    # answered, but late: visible in metrics so operators
-                    # can tell "meets deadlines" from "merely completes"
-                    self._metrics.count("deadline_missed")
-            self._metrics.count("completed", len(group))
-            self._release(group)
+            with jax.profiler.TraceAnnotation("serve.d2h"):
+                slab = ResultSlab(
+                    data=np.asarray(out),
+                    widths=tuple(e.request.y0.shape[1] for e in group))
+            with jax.profiler.TraceAnnotation("serve.resolve"):
+                t_done = self._clock()
+                for k, entry in enumerate(group):
+                    entry.future.set_result(slab.view(k))
+                    self._metrics.record_latency(t_done - entry.t_submit)
+                    if entry.t_deadline is not None and t_done > entry.t_deadline:
+                        # answered, but late: visible in metrics so operators
+                        # can tell "meets deadlines" from "merely completes"
+                        self._metrics.count("deadline_missed")
+                self._metrics.count("completed", len(group))
+                self._release(group)
             resolved += len(group)
         return resolved
 
@@ -936,14 +963,16 @@ class PropagateEngine(Engine):
             # resume primitive (label_propagate_resume rejects grf)
             out = self._scan(vdt, stack, alphas, n_iters, "grf",
                              n_walkers=n_walkers)
-            jax.block_until_ready(out)
+            with jax.profiler.TraceAnnotation("serve.wait"):
+                jax.block_until_ready(out)
             return out, 0
         # segment only when this configuration actually preempts — the
         # capability the engine itself reports, not an attribute probe
         if (not preemptible or "preempt" not in self.capabilities()
                 or int(n_iters) <= seg):
             out = self._scan(vdt, stack, alphas, n_iters, backend)
-            jax.block_until_ready(out)
+            with jax.profiler.TraceAnnotation("serve.wait"):
+                jax.block_until_ready(out)
             return out, 0
         # device-resident seed: urgent dispatches between segments refill
         # the SAME staging buffers, so the suspended walk's restart term
@@ -959,7 +988,8 @@ class PropagateEngine(Engine):
             t0 = self._clock()
             rec.carry = self._scan_resume(vdt, rec.carry, rec.y0,
                                           rec.alphas, k, rec.backend)
-            jax.block_until_ready(rec.carry)
+            with jax.profiler.TraceAnnotation("serve.wait"):
+                jax.block_until_ready(rec.carry)
             dt = max(self._clock() - t0, 0.0)
             rec.iters_done += k
             with self._state_lock:

@@ -82,10 +82,11 @@ AUTO_GRF_MAX_DENSITY = 0.05
 AUTO_GRF_MIN_RTOL = 0.05
 
 # widest column slab one VDT walk materializes.  Each iteration gathers and
-# segment-sums (|B| slots, K) f32 arrays: compiled for a v5e at SecStr size
-# (2^17 leaves, |B| = 4N), the scan holds 2.25 GB of temporaries at K = 512
-# and 25.5 GB — past the chip's 16 GB — at the engine's widest K = 4096.
-# LP is column-independent, so wider walks run slab by slab (2.5 GB there).
+# segment-sums (slots, K) f32 arrays over the scan table: compiled for a
+# v5e at SecStr size (2^17 leaves, |B| = 4N in 344,064 slots), the scan
+# holds 1.07 GB of temporaries at K = 512 and 9.9 GB, most of the chip's
+# 16 GB, at the engine's widest K = 4096.  LP is column-independent, so
+# wider walks run slab by slab (1.9 GB there).
 VDT_MAX_COLS = 512
 
 # the three concrete scan implementations every routing tag resolves to —
@@ -191,9 +192,9 @@ def column_slabs(walk, cols, alpha):
 def lp_scan_leaforder(
     y0_leaf: jax.Array,      # (Np, K) seed labels in leaf order (ghosts 0)
     leaf_mask: jax.Array,    # (Np, 1) 1.0 at real leaves, 0.0 at ghosts
-    a: jax.Array,            # (cap,) block row nodes
-    b: jax.Array,            # (cap,) block col nodes
-    q: jax.Array,            # (cap,) exp(log_q), 0 where inactive
+    a: jax.Array,            # (slots,) scan table (matvec.scan_table):
+    b: jax.Array,            # (slots,)   row nodes sorted, col nodes,
+    q: jax.Array,            # (slots,)   exp(log_q), 0 at the pads
     alpha: jax.Array,        # () or (K,) — traced, NOT part of the jit key
     L: int,
     n_iters: int,

@@ -31,6 +31,16 @@ served two equivalent ways:
 path; ``mpt_matvec_batched`` is the explicit spelling.  Parity of both paths
 against stacked single-RHS calls (and against the dense ``Q @ Y``) is pinned
 in ``tests/test_batched.py``.
+
+The scan table
+--------------
+The per-block phase reads a *scan table* ``(a, b, q)`` built once per
+fitted model by :func:`scan_table`: the active blocks alone, sorted by row
+node ``a`` and then by ``b``, padded to a length bucket with blocks whose
+row node ``a = n_nodes`` lies past the last segment.  The block partition's
+own arrays keep refinement's capacity, most of it inactive slots; the scan
+walks only what carries mass, and the segment-sum is told its ids are
+sorted, so it does not sort them again every step.
 """
 from __future__ import annotations
 
@@ -38,6 +48,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.tree import PartitionTree
 
@@ -48,6 +59,9 @@ __all__ = [
     "mpt_matvec_batched",
     "mpt_matvec_leaforder",
     "prepare_q",
+    "scan_slots",
+    "scan_table",
+    "table_matvec",
     "unfold_batch",
 ]
 
@@ -61,6 +75,40 @@ def prepare_q(active: jax.Array, log_q: jax.Array) -> jax.Array:
     inside every scan step.
     """
     return jnp.where(active & jnp.isfinite(log_q), jnp.exp(log_q), 0.0)
+
+
+def scan_slots(n_blocks: int) -> int:
+    """Length of the scan table that holds ``n_blocks`` active blocks.
+
+    The next multiple of ``2**(floor(log2 n) - 4)``: at most 1/16 padding,
+    and an active count that moves a little (a streaming publish) keeps
+    the length, so the scan keeps its compiled program.
+    """
+    n = max(int(n_blocks), 1)
+    granule = 1 << max(n.bit_length() - 5, 0)
+    return -(-n // granule) * granule
+
+
+def scan_table(a, b, active, q, n_nodes: int) -> tuple:
+    """The block table the scan walks, ``(a, b, q)`` as host arrays.
+
+    Keeps the ``active`` blocks of the capacity arrays ``a``, ``b``, ``q``,
+    sorted by ``(a, b)`` so that each row node's blocks form one run and
+    their ``T[b]`` reads stay close, and pads to :func:`scan_slots` with
+    ``a = n_nodes``, ``b = 0``, ``q = 0``: the segment-sum drops ids past
+    its last segment, and ``n_nodes`` keeps the ids sorted.  The only
+    producer of what :func:`mpt_matvec_leaforder` reads.
+    """
+    a, b = np.asarray(a, np.int32), np.asarray(b, np.int32)
+    q = np.asarray(q, np.float32)
+    idx = np.flatnonzero(np.asarray(active, bool))
+    idx = idx[np.lexsort((b[idx], a[idx]))]
+    n, slots = idx.size, scan_slots(idx.size)
+    ta = np.full(slots, n_nodes, np.int32)
+    tb = np.zeros(slots, np.int32)
+    tq = np.zeros(slots, np.float32)
+    ta[:n], tb[:n], tq[:n] = a[idx], b[idx], q[idx]
+    return ta, tb, tq
 
 
 def fold_batch(ys: jax.Array) -> jax.Array:
@@ -118,25 +166,28 @@ def _distribute_down(c_node: jax.Array, L: int) -> jax.Array:
 @functools.partial(jax.jit, static_argnames=("L",))
 def mpt_matvec_leaforder(
     y_leaf: jax.Array,       # (..., Np, C) values in leaf order (ghosts 0)
-    a: jax.Array,            # (cap,)
-    b: jax.Array,            # (cap,)
-    q: jax.Array,            # (cap,)  block parameters (0 where inactive)
+    a: jax.Array,            # (slots,) row nodes, sorted; n_nodes pads
+    b: jax.Array,            # (slots,) column nodes
+    q: jax.Array,            # (slots,) block parameters (0 at the pads)
     L: int,
 ) -> jax.Array:
     """(QY) in leaf order; any leading batch dims ride along level-major.
 
-    Each phase runs under a ``jax.named_scope`` (``vdt.collect_up``,
-    ``vdt.gather``, ``vdt.segment_sum``, ``vdt.distribute_down``), so the
-    ops of a profile carry the phase in their op name.
+    ``(a, b, q)`` is a table from :func:`scan_table`: the segment-sum
+    takes its row nodes as sorted.  Each phase runs under a
+    ``jax.named_scope`` (``vdt.collect_up``, ``vdt.gather``,
+    ``vdt.segment_sum``, ``vdt.distribute_down``), so the ops of a profile
+    carry the phase in their op name.
     """
     n_nodes = (1 << (L + 1)) - 1
     with jax.named_scope("vdt.collect_up"):
         t = collect_up(y_leaf, L)                       # (..., n_nodes, C)
     with jax.named_scope("vdt.gather"):
-        c_block = q[:, None] * jnp.take(t, b, axis=-2)  # (..., cap, C)
+        c_block = q[:, None] * jnp.take(t, b, axis=-2)  # (..., slots, C)
     with jax.named_scope("vdt.segment_sum"):
-        c_block = jnp.moveaxis(c_block, -2, 0)          # (cap, ..., C)
-        c_node = jax.ops.segment_sum(c_block, a, num_segments=n_nodes)
+        c_block = jnp.moveaxis(c_block, -2, 0)          # (slots, ..., C)
+        c_node = jax.ops.segment_sum(c_block, a, num_segments=n_nodes,
+                                     indices_are_sorted=True)
         c_node = jnp.moveaxis(c_node, 0, -2)            # (..., n_nodes, C)
     with jax.named_scope("vdt.distribute_down"):
         return _distribute_down(c_node, L)
@@ -152,21 +203,33 @@ def mpt_matvec(
 ) -> jax.Array:
     """(QY) in original row order; O(|B| C + N C).
 
+    Takes the block partition's capacity arrays, concrete even where ``y``
+    is traced, and builds their :func:`scan_table` on the host for the
+    call; a fitted model keeps its table (``VariationalDualTree.matvec``).
+    """
+    with jax.ensure_compile_time_eval():
+        q = prepare_q(jnp.asarray(active), jnp.asarray(log_q))
+    table = scan_table(a, b, active, q, tree.n_nodes)
+    return table_matvec(tree, table, y)
+
+
+def table_matvec(tree: PartitionTree, table: tuple, y: jax.Array) -> jax.Array:
+    """(QY) in original row order over a :func:`scan_table` ``(a, b, q)``.
+
     A 3-D ``y`` of shape ``(batch, N, C)`` is served by one device dispatch
     via channel folding: ``(batch, N, C) -> (N, batch * C)``.
     """
     y = jnp.asarray(y)
     if y.ndim == 3:
         batch, _, c = y.shape
-        out = mpt_matvec(tree, a, b, active, log_q, fold_batch(y))
+        out = table_matvec(tree, table, fold_batch(y))
         return unfold_batch(out, batch, c)
     squeeze = y.ndim == 1
     if squeeze:
         y = y[:, None]
-    q = prepare_q(active, log_q)
     y_leaf = jnp.zeros((tree.n_leaves, y.shape[1]), dtype=y.dtype)
     y_leaf = y_leaf.at[tree.slot_of].set(y)
-    out_leaf = mpt_matvec_leaforder(y_leaf, a, b, q, tree.L)
+    out_leaf = mpt_matvec_leaforder(y_leaf, *table, tree.L)
     out = out_leaf[tree.slot_of]
     return out[:, 0] if squeeze else out
 

@@ -65,6 +65,7 @@ import numpy as np
 
 from repro.core import blocks as blocks_mod
 from repro.core import divergence as div_mod
+from repro.core import matvec as matvec_mod
 from repro.core import qopt as qopt_mod
 from repro.core.tree import PartitionTree
 from repro.core.vdt import VariationalDualTree
@@ -476,7 +477,8 @@ def _commit(vdt: VariationalDualTree, state: _StreamState,
     state.bp_n, state.cap = bp.n, bp.cap
 
     new_stats = dataclasses.replace(
-        vdt.stats, n_blocks=bp.n_active, bound=float(qs.bound))
+        vdt.stats, n_blocks=bp.n_active,
+        scan_slots=matvec_mod.scan_slots(bp.n_active), bound=float(qs.bound))
     new_vdt = VariationalDualTree(
         tree=tree, bp=bp, qstate=qs, sigma=vdt.sigma, stats=new_stats,
         divergence=bound)
@@ -516,6 +518,7 @@ def recompute(vdt: VariationalDualTree) -> VariationalDualTree:
         tree, jnp.asarray(bp.a), jnp.asarray(bp.b), jnp.asarray(active),
         vdt.sigma, divergence=bound)
     stats = dataclasses.replace(
-        vdt.stats, n_blocks=bp.n_active, bound=float(qs.bound))
+        vdt.stats, n_blocks=bp.n_active,
+        scan_slots=matvec_mod.scan_slots(bp.n_active), bound=float(qs.bound))
     return VariationalDualTree(tree=tree, bp=bp, qstate=qs, sigma=vdt.sigma,
                                stats=stats, divergence=bound)

@@ -44,6 +44,9 @@ class VdtStats:
     refine_select_s: float = 0.0
     sigma_iters: int = 0
     n_blocks: int = 0
+    # length of the block table the VDT scan walks: n_blocks padded to
+    # its bucket (matvec.scan_slots)
+    scan_slots: int = 0
     bound: float = 0.0
     sigma: float = 0.0
     divergence: str = "sqeuclidean"
@@ -60,9 +63,9 @@ class VariationalDualTree:
     # (block-stats precomputed); None means the default Gaussian kernel and
     # is lazily normalized to the bound sqeuclidean divergence
     divergence: Optional[div_mod.BoundDivergence] = None
-    # device-resident dispatch buffers (a, b, active, q, leaf_mask), built
-    # lazily and reused across serving calls / scheduler iterations; q never
-    # changes between refinements so re-deriving it per call is pure waste.
+    # device-resident dispatch buffers (the scan table a, b, q and the
+    # leaf mask), built lazily and reused across serving calls / scheduler
+    # iterations; the table never changes between refinements.
     _serve_cache: Optional[tuple] = dataclasses.field(
         default=None, init=False, repr=False, compare=False)
     # points in original row order (exact-backend LP reads them); derived
@@ -158,6 +161,7 @@ class VariationalDualTree:
             stats.refine_s = time.perf_counter() - t0
 
         stats.n_blocks = bp.n_active
+        stats.scan_slots = matvec_mod.scan_slots(bp.n_active)
         stats.bound = float(qs.bound)
         stats.sigma = float(sig)
         return cls(tree=tree, bp=bp, qstate=qs, sigma=sig, stats=stats,
@@ -177,22 +181,27 @@ class VariationalDualTree:
         return self.bound_divergence.name
 
     def _dispatch_buffers(self) -> tuple:
-        """(a, b, active, q, leaf_mask) on device, cached across calls.
+        """(a, b, q, leaf_mask) on device, cached across calls.
 
-        ``leaf_mask`` is 1.0 exactly at leaf slots holding a real row (so the
-        leaf-order LP scan can keep ghost slots at zero); ``q`` is the
-        ready-to-use ``exp(log_q)`` from :func:`~repro.core.matvec.prepare_q`.
-        Invalidated by :meth:`refine`.
+        ``(a, b, q)`` is the scan table of
+        :func:`~repro.core.matvec.scan_table`: the active blocks sorted by
+        row node, with the ready-to-use ``exp(log_q)``.  ``leaf_mask`` is
+        1.0 exactly at leaf slots holding a real row (so the leaf-order LP
+        scan can keep ghost slots at zero).  Invalidated by :meth:`refine`.
         """
         if self._serve_cache is None:
-            a = jnp.asarray(self.bp.a)
-            b = jnp.asarray(self.bp.b)
-            active = jnp.asarray(self.bp.active)
-            q = matvec_mod.prepare_q(active, self.qstate.log_q)
-            mask = jnp.zeros((self.tree.n_leaves, 1), jnp.float32)
-            mask = mask.at[self.tree.slot_of, 0].set(1.0)
+            # eager even when the first call comes from inside a trace (a
+            # matvec closure under lax.scan): the table is built on the host
+            # and the cache outlives the trace
+            with jax.ensure_compile_time_eval():
+                q = matvec_mod.prepare_q(jnp.asarray(self.bp.active),
+                                         self.qstate.log_q)
+                a, b, q = (jnp.asarray(t) for t in matvec_mod.scan_table(
+                    self.bp.a, self.bp.b, self.bp.active, q, self.tree.n_nodes))
+                mask = jnp.zeros((self.tree.n_leaves, 1), jnp.float32)
+                mask = mask.at[self.tree.slot_of, 0].set(1.0)
             jax.block_until_ready(q)
-            self._serve_cache = (a, b, active, q, mask)
+            self._serve_cache = (a, b, q, mask)
         return self._serve_cache
 
     @property
@@ -209,17 +218,15 @@ class VariationalDualTree:
         ``(batch, N, C)``; the latter is served in ONE device dispatch via
         the channel-folded batched path (see ``core.matvec``).
         """
-        a, b, active, _, _ = self._dispatch_buffers()
-        return matvec_mod.mpt_matvec(
-            self.tree, a, b, active, self.qstate.log_q, y,
-        )
+        a, b, q, _ = self._dispatch_buffers()
+        return matvec_mod.table_matvec(self.tree, (a, b, q), y)
 
     def matvec_batched(self, ys) -> jax.Array:
         """Explicit batched multi-RHS: (batch, N, C) -> (batch, N, C)."""
-        a, b, active, _, _ = self._dispatch_buffers()
-        return matvec_mod.mpt_matvec_batched(
-            self.tree, a, b, active, self.qstate.log_q, ys,
-        )
+        ys = jnp.asarray(ys)
+        if ys.ndim != 3:
+            raise ValueError(f"matvec_batched wants (batch, N, C), got {ys.shape}")
+        return self.matvec(ys)
 
     def grf_graph(self):
         """The CSR transition graph the GRF backend walks, cached.
@@ -337,7 +344,7 @@ class VariationalDualTree:
         if squeeze:
             y0 = y0[:, None]
         tree = self.tree
-        a, b, _, q, mask = self._dispatch_buffers()
+        a, b, q, mask = self._dispatch_buffers()
         with jax.profiler.TraceAnnotation("vdt.permute"):
             y_leaf = jnp.zeros((tree.n_leaves, y0.shape[1]), y0.dtype)
             y_leaf = y_leaf.at[tree.slot_of].set(y0)
@@ -415,7 +422,7 @@ class VariationalDualTree:
         if squeeze:
             y, y0 = y[:, None], y0[:, None]
         tree = self.tree
-        a, b, _, q, mask = self._dispatch_buffers()
+        a, b, q, mask = self._dispatch_buffers()
         # ghost slots are zero both in the seed and (by the re-masking
         # invariant) in any mid-walk carry, so scattering the row-order
         # carry into zeros reproduces the in-scan leaf state exactly
@@ -457,9 +464,10 @@ class VariationalDualTree:
             self.bp, self.tree, self.sigma, max_blocks, batch=batch,
             divergence=self.bound_divergence, stale=stale, stats=self.stats,
         )
-        self._serve_cache = None  # a/b/q/active all changed
+        self._serve_cache = None  # the scan table changed
         self._stream = None  # refinement regrew the partition; mirrors stale
         self.stats.n_blocks = self.bp.n_active
+        self.stats.scan_slots = matvec_mod.scan_slots(self.bp.n_active)
         self.stats.bound = float(self.qstate.bound)
         self.stats.refine_s += time.perf_counter() - t0
 
@@ -491,9 +499,10 @@ class VariationalDualTree:
     def lower_bound(self, log_q=None) -> jax.Array:
         """l(D) for ``log_q`` (default: the fitted q) under the fitted divergence."""
         self._check_finite_q()
-        a, b, active, _, _ = self._dispatch_buffers()
         lq = self.qstate.log_q if log_q is None else jnp.asarray(log_q)
-        return qopt_mod.lower_bound(self.tree, a, b, active, lq, self.sigma,
+        return qopt_mod.lower_bound(self.tree, jnp.asarray(self.bp.a),
+                                    jnp.asarray(self.bp.b),
+                                    jnp.asarray(self.bp.active), lq, self.sigma,
                                     divergence=self.bound_divergence)
 
     @property

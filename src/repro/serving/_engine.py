@@ -1057,12 +1057,13 @@ class PropagateEngine(Engine):
             stale_blocks = self._stale_blocks
             live_epochs = len(self._epochs)
             n_walkers = self._last_n_walkers
+            scan_slots = self.vdt.stats.scan_slots
         return self._metrics.snapshot(
             queue_depth=len(self._queue), in_flight=in_flight,
             dispatch_key=self.dispatch_key, policy=self.policy,
             linger_window_ms=linger_window_ms, epoch=epoch,
             stale_blocks=stale_blocks, live_epochs=live_epochs,
-            n_walkers=n_walkers)
+            n_walkers=n_walkers, scan_slots=scan_slots)
 
     def shutdown(self, wait: bool = True) -> None:
         """Stop accepting work; serve (``wait=True``) or cancel the backlog.

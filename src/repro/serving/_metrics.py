@@ -82,6 +82,9 @@ class MetricsSnapshot:
     #   (gauge; 0 = no grf group dispatched yet).  A grf group dispatches
     #   at the MAX budget over its members, so this is the budget actual
     #   device work ran at — the accuracy-vs-latency dial operators watch
+    scan_slots: int = 0  # block slots each step of the current epoch's VDT
+    #   walk reads: its active blocks padded to a length bucket (gauge;
+    #   ``VdtStats.scan_slots``)
     queue_depth: int = 0  # entries waiting right now (gauge)
     in_flight: int = 0  # drained but not yet resolved (gauge)
     linger_window_ms: float = float("nan")  # current adaptive batching window
@@ -145,6 +148,7 @@ class EngineMetrics:
         stale_blocks: int = 0,
         live_epochs: int = 1,
         n_walkers: int = 0,
+        scan_slots: int = 0,
     ) -> MetricsSnapshot:
         with self._lock:
             lat = sorted(self._latencies_ms)
@@ -160,6 +164,7 @@ class EngineMetrics:
             stale_blocks=stale_blocks,
             live_epochs=live_epochs,
             n_walkers=n_walkers,
+            scan_slots=scan_slots,
             latency_p50_ms=_quantile(lat, 0.50),
             latency_p95_ms=_quantile(lat, 0.95),
             latency_mean_ms=mean,

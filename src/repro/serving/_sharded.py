@@ -119,10 +119,12 @@ def _sharded_matvec(y_sh, a, b, q, *, L: int, K: int, axis: str):
             parts.append(t_all[:, lo:hi, :].reshape(-1, t_all.shape[-1]))
         t_full = jnp.concatenate(parts, axis=0)    # (n_nodes, C) level-major
     n_nodes = (1 << (L + 1)) - 1
-    # per-block contraction + segment-sum: O(|B| C), replicated — every
-    # device computes the identical c_node, so no psum is ever needed
+    # per-block contraction + segment-sum over the scan table (sorted row
+    # nodes, as the single-device matvec takes them): O(|B| C), replicated
+    # — every device computes the identical c_node, so no psum is needed
     c_block = q[:, None] * jnp.take(t_full, b, axis=0)
-    c_node = jax.ops.segment_sum(c_block, a, num_segments=n_nodes)
+    c_node = jax.ops.segment_sum(c_block, a, num_segments=n_nodes,
+                                 indices_are_sorted=True)
     # DistributeDown: replicated down to level K, then into our subtree
     acc = c_node[0:1, :]
     d = jax.lax.axis_index(axis)
@@ -212,7 +214,7 @@ class ShardedPropagateEngine(PropagateEngine):
     def _buffers(self, vdt) -> dict:
         buf = self._buf_cache.get(id(vdt))
         if buf is None:
-            a, b, active, q, mask = vdt._dispatch_buffers()
+            a, b, q, mask = vdt._dispatch_buffers()
             tree = vdt.tree
             # place once per epoch: block lists / q replicated over the
             # mesh, the ghost mask row-sharded with the label stripes

@@ -12,7 +12,7 @@ from _hyp import given, settings, st
 import jax.numpy as jnp
 
 from repro.core.matvec import (collect_up, mpt_matvec, mpt_matvec_batched,
-                               mpt_matvec_leaforder)
+                               mpt_matvec_leaforder, prepare_q, scan_table)
 from repro.kernels.fused_lp import (fused_lp_matvec_batched,
                                     fused_lp_matvec_batched_ref,
                                     fused_lp_scan_batched,
@@ -60,9 +60,9 @@ def test_level_major_leaforder_accepts_leading_batch(small_fitted_vdt):
                     for i in range(4)])
     np.testing.assert_allclose(t_b, t_s, rtol=1e-6, atol=1e-6)
 
-    q = jnp.where(jnp.asarray(vdt.bp.active) & jnp.isfinite(vdt.qstate.log_q),
-                  jnp.exp(vdt.qstate.log_q), 0.0)
-    a, b = jnp.asarray(vdt.bp.a), jnp.asarray(vdt.bp.b)
+    q = prepare_q(jnp.asarray(vdt.bp.active), vdt.qstate.log_q)
+    a, b, q = scan_table(vdt.bp.a, vdt.bp.b, vdt.bp.active, q, tree.n_nodes)
+    assert (np.diff(a) >= 0).all()  # the segment-sum takes them as sorted
     o_b = np.asarray(mpt_matvec_leaforder(jnp.asarray(y_leaf), a, b, q, tree.L))
     o_s = np.stack(
         [np.asarray(mpt_matvec_leaforder(jnp.asarray(y_leaf[i]), a, b, q,

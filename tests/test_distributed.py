@@ -88,7 +88,7 @@ def test_distributed_vdt_lp_step_matches_reference():
         from repro.core.tree import build_tree
         from repro.core.blocks import coarsest_partition
         from repro.core.qopt import optimize_q
-        from repro.core.matvec import mpt_matvec_leaforder
+        from repro.core.matvec import mpt_matvec_leaforder, scan_table
 
         r = np.random.RandomState(0)
         n, d, c = 1024, 8, 4
@@ -102,8 +102,9 @@ def test_distributed_vdt_lp_step_matches_reference():
         y0 = jnp.asarray(r.randn(n, c), jnp.float32)
         alpha = 0.3
 
-        ref = alpha * mpt_matvec_leaforder(y, jnp.asarray(bp.a),
-                                           jnp.asarray(bp.b), q, tree.L) \\
+        ta, tb, tq = scan_table(bp.a, bp.b, bp.active, q, tree.n_nodes)
+        assert (np.diff(ta) >= 0).all()  # the segment-sum takes them as sorted
+        ref = alpha * mpt_matvec_leaforder(y, ta, tb, tq, tree.L) \\
               + (1 - alpha) * y0
 
         # pad blocks to a shard-divisible count with inert q=0 entries

@@ -65,7 +65,7 @@ def test_leaforder_segmented_bit_identical(golden_vdt, seg, width):
     rng = np.random.RandomState(11)
     y0 = (rng.rand(x.shape[0], width) > 0.7).astype(np.float32)
     tree = vdt.tree
-    a, b, _, q, mask = vdt._dispatch_buffers()
+    a, b, q, mask = vdt._dispatch_buffers()
     y0_leaf = np.zeros((tree.n_leaves, width), np.float32)
     y0_leaf[np.asarray(tree.slot_of)] = y0
     alpha = np.float32(0.02)

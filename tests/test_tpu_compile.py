@@ -117,3 +117,20 @@ def test_pairwise_kernel_compiles(one_chip):
         functools.partial(pairwise_sq_dists_kernel, interpret=False),
         ((4096, D), jnp.float32), ((N, D), jnp.float32), sharding=one_chip)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("k", [2, 64, 4096])  # one request to a full slab walk
+def test_vdt_scan_compiles_without_a_sort(one_chip, k):
+    """The VDT walk over SecStr's scan table (4N blocks, 2^17 leaves): the
+    segment-sum takes its row nodes as sorted, so no sort runs per step."""
+    from repro.core.label_prop import lp_scan_leaforder
+    from repro.core.matvec import scan_slots
+
+    L = 17
+    slots = scan_slots(4 * N)
+    fn = functools.partial(lp_scan_leaforder.__wrapped__, L=L, n_iters=50)
+    f32, i32 = jnp.float32, jnp.int32
+    compiled = _compile(fn, ((1 << L, k), f32), ((1 << L, 1), f32),
+                        ((slots,), i32), ((slots,), i32), ((slots,), f32),
+                        ((k,), f32), sharding=one_chip)
+    assert " sort(" not in compiled.as_text()
